@@ -13,7 +13,10 @@ measurement runs in a subprocess (``weak_scaling_main.py``, mirroring
 ``ROW|name|us|derived`` lines into suite rows. Note the virtual devices
 share one physical CPU: ``weak_efficiency`` reflects the *software*
 overhead of sharding (collective + per-shard bookkeeping), not real
-multi-chip bandwidth.
+multi-chip bandwidth. It is a CPU study only: a process that already
+holds an accelerator would leave the chip to a child that cannot have
+it, so :func:`run` refuses to start there. The sharded store's path on
+real chips is ``python chip_smoke.py --chips 4``.
 """
 from __future__ import annotations
 
@@ -27,6 +30,14 @@ HELPER = Path(__file__).resolve().parent / "weak_scaling_main.py"
 
 
 def run(rows):
+    import jax
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "fig6dev measures sharding overhead on virtual CPU devices in "
+            f"a child process; this process runs on {jax.default_backend()}"
+            ", and the chip belongs to one process at a time. Run the "
+            "sharded store on the chip with `python chip_smoke.py "
+            "--chips 4` instead.")
     cmd = [sys.executable, str(HELPER)] + (["--smoke"] if smoke() else [])
     r = subprocess.run(cmd, capture_output=True, text=True, timeout=1800)
     if r.returncode != 0:

@@ -39,6 +39,7 @@ from . import (bench_block_page_ops, bench_cleans, bench_io_costs,
                bench_serving, bench_weak_scaling)
 from .common import (compare_to_baseline, emit, rows_to_json, set_slow,
                      set_smoke)
+from repro.compile_cache import enable_compile_cache
 
 SUITES = {
     "fig3": bench_query_times,
@@ -68,6 +69,7 @@ def main() -> None:
                          "BENCH_PR*.json; exit 1 if any speedup falls "
                          "below its floor")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         set_smoke()
     if args.slow:

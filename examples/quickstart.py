@@ -10,7 +10,10 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import FlashStore
+
+enable_compile_cache()
 
 rng = np.random.default_rng(0)
 tokens = (rng.zipf(1.4, size=200_000) % (1 << 20)).astype(np.int64)

@@ -8,9 +8,11 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.serve import main
 
 if __name__ == "__main__":
+    enable_compile_cache()
     if "--arch" not in sys.argv:
         sys.argv += ["--arch", "llama32_3b", "--tiny", "--requests", "8",
                      "--prompt-len", "32", "--shared-prefix", "24",

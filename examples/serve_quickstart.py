@@ -11,11 +11,13 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.models import model as M
 from repro.serving import (ContinuousBatchingScheduler, PrefixKVCache,
                            make_trace, replay_trace)
 
+enable_compile_cache()
 cfg = dataclasses.replace(get_config("llama32_3b", tiny=True),
                           dtype="float32")
 params = M.init_params(jax.random.PRNGKey(0), cfg)

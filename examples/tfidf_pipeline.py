@@ -10,6 +10,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import TableGeometry
 from repro.core.tfidf import TfIdfPipeline, tokenize
 from repro.data import CorpusStats, LoaderConfig, SyntheticCorpus, make_batch
@@ -22,6 +23,7 @@ DOCS = [
     "solid state drives wear out after too many erase write cycles",
 ] * 20
 
+enable_compile_cache()
 print("=== TF-IDF over the counting hash table (paper §3.2) ===")
 # every table behind the pipeline is a FlashStore (DESIGN.md §8);
 # backend="sim" | "device" | "sharded" swaps the engine with no other change

@@ -11,9 +11,11 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.train import main
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.argv += ["--tiny", "--steps", "200", "--ckpt-dir",
                  "/tmp/repro_ckpt"] if "--steps" not in sys.argv else []
     main()

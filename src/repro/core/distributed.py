@@ -182,16 +182,15 @@ def make_update_fn(cfg: ShardedTableConfig, mesh, axis: str,
             (carry_k != EMPTY).sum(dtype=jnp.int32), axis)
         return _expand(state, local_cfg), n_carry
 
-    from jax.experimental.shard_map import shard_map
     if with_deltas:
         body = local_update
         in_specs = (spec, P(axis), P(axis))
     else:
         body = lambda state, tokens: local_update(state, tokens)
         in_specs = (spec, P(axis))
-    upd = shard_map(body, mesh=mesh, in_specs=in_specs,
-                    out_specs=(spec, P()),
-                    check_rep=False)
+    upd = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                        out_specs=(spec, P()),
+                        check_vma=False)
     return jax.jit(upd, donate_argnums=(0,) if donate else ())
 
 
@@ -229,16 +228,15 @@ def make_lookup_fn(cfg: ShardedTableConfig, mesh, axis: str,
             return cnt, dist
         return cnt, dist, jax.lax.psum(tiles, axis)
 
-    from jax.experimental.shard_map import shard_map
     if with_tiles and not with_dist:
         raise ValueError("with_tiles requires with_dist")
     out_specs = (P() if not with_dist
                  else (P(), P(), P()) if with_tiles
                  else (P(), P()))
-    look = shard_map(local_lookup, mesh=mesh,
-                     in_specs=(spec, P()),
-                     out_specs=out_specs,
-                     check_rep=False)
+    look = jax.shard_map(local_lookup, mesh=mesh,
+                         in_specs=(spec, P()),
+                         out_specs=out_specs,
+                         check_vma=False)
     return jax.jit(look)
 
 
@@ -263,11 +261,10 @@ def make_filter_fn(cfg: ShardedTableConfig, mesh, axis: str):
         return jax.lax.psum(
             jnp.where(mine, may, False).astype(jnp.int32), axis)
 
-    from jax.experimental.shard_map import shard_map
-    filt = shard_map(local_filter, mesh=mesh,
-                     in_specs=(spec, P()),
-                     out_specs=P(),
-                     check_rep=False)
+    filt = jax.shard_map(local_filter, mesh=mesh,
+                         in_specs=(spec, P()),
+                         out_specs=P(),
+                         check_vma=False)
     return jax.jit(filt)
 
 
@@ -283,9 +280,8 @@ def make_flush_fn(cfg: ShardedTableConfig, mesh, axis: str,
         return _expand(tj.flush(local_cfg, _squeeze(state, local_cfg)),
                        local_cfg)
 
-    from jax.experimental.shard_map import shard_map
-    fl = shard_map(local_flush, mesh=mesh, in_specs=(spec,),
-                   out_specs=spec, check_rep=False)
+    fl = jax.shard_map(local_flush, mesh=mesh, in_specs=(spec,),
+                       out_specs=spec, check_vma=False)
     return jax.jit(fl, donate_argnums=(0,) if donate else ())
 
 
@@ -372,7 +368,6 @@ def make_sync_fn(cfg: ShardedTableConfig, mesh, axis: str, width: int = 2):
     def local_max(v):  # v: (1, width) per shard
         return jax.lax.pmax(v.reshape(v.shape[1:]), axis)
 
-    from jax.experimental.shard_map import shard_map
-    sync = shard_map(local_max, mesh=mesh, in_specs=(P(axis),),
-                     out_specs=P(), check_rep=False)
+    sync = jax.shard_map(local_max, mesh=mesh, in_specs=(P(axis),),
+                         out_specs=P(), check_vma=False)
     return jax.jit(sync)

@@ -25,7 +25,7 @@ Shared primitives
   batch (the in-kernel RAM-buffer analogue).
 
 Functions take the table config duck-typed (anything with ``pair``,
-``num_blocks``, ``max_updates_per_block``, ``interpret`` and — for the
+``num_blocks``, ``max_updates_per_block`` and — for the
 partitioned ops — ``cs_partitions`` / ``blocks_per_partition`` /
 ``partition_capacity``), so this module has no import cycle with
 :mod:`table_jax`.
@@ -54,8 +54,9 @@ class TableStats(NamedTuple):
 
 
 class DeviceTableState(NamedTuple):
-    keys: jax.Array        # (n_b, r) int32 — data segment
-    counts: jax.Array      # (n_b, r) int32
+    keys: jax.Array        # (n_b, 1, r) int32 — data segment, one row
+                           # per block in the kernels' tile layout
+    counts: jax.Array      # (n_b, 1, r) int32
     log_keys: jax.Array    # change segment: (log_cap,) for MDB-L,
                            # (cs_partitions, part_cap) for MDB
     log_counts: jax.Array  # same shape as log_keys
@@ -63,7 +64,7 @@ class DeviceTableState(NamedTuple):
     ov_keys: jax.Array     # (ov_cap,) int32 — overflow region
     ov_counts: jax.Array   # (ov_cap,) int32
     ov_ptr: jax.Array      # () int32
-    filter_words: jax.Array  # (n_b, fw) uint32 — per-block blocked-Bloom
+    filter_words: jax.Array  # (n_b, 1, fw) uint32 — per-block blocked-Bloom
                              # filter rows (DESIGN.md §12). Monotone: bits
                              # are only ever OR'd in, covering every key in
                              # the data/change/overflow segments, so a
@@ -82,15 +83,15 @@ def init_state(num_blocks: int, block_entries: int, log_shape,
                filter_words: int) -> DeviceTableState:
     """Fresh segment state: EMPTY data/change/overflow regions."""
     return DeviceTableState(
-        keys=jnp.full((num_blocks, block_entries), EMPTY, jnp.int32),
-        counts=jnp.zeros((num_blocks, block_entries), jnp.int32),
+        keys=jnp.full((num_blocks, 1, block_entries), EMPTY, jnp.int32),
+        counts=jnp.zeros((num_blocks, 1, block_entries), jnp.int32),
         log_keys=jnp.full(log_shape, EMPTY, jnp.int32),
         log_counts=jnp.zeros(log_shape, jnp.int32),
         log_ptr=jnp.zeros(log_ptr_shape, jnp.int32),
         ov_keys=jnp.full((overflow_capacity,), EMPTY, jnp.int32),
         ov_counts=jnp.zeros((overflow_capacity,), jnp.int32),
         ov_ptr=jnp.zeros((), jnp.int32),
-        filter_words=jnp.zeros((num_blocks, filter_words), jnp.uint32),
+        filter_words=jnp.zeros((num_blocks, 1, filter_words), jnp.uint32),
         stats=zero_stats(),
     )
 
@@ -113,7 +114,7 @@ def filter_or_keys(pair, filt, keys):
     ``.at[].add`` the single-bit masks — after dedup all bits are
     distinct, so add ≡ or.
     """
-    n_b, fw = filt.shape
+    n_b, _, fw = filt.shape
     bits_log2 = (fw * 32).bit_length() - 1
     valid = keys != EMPTY
     blk = jnp.where(valid, pair.s(keys), n_b).astype(jnp.int32)
@@ -128,8 +129,9 @@ def filter_or_keys(pair, filt, keys):
         is_head,
         jnp.left_shift(jnp.int32(1), fids & 31).astype(jnp.uint32),
         jnp.uint32(0))
-    new = jnp.zeros((n_b * fw,), jnp.uint32).at[word].add(mask, mode="drop")
-    return filt | new.reshape(n_b, fw)
+    new = jnp.zeros_like(filt).at[word // fw, 0, word % fw].add(
+        mask, mode="drop")
+    return filt | new
 
 
 def filter_may_contain(pair, filt, q):
@@ -141,13 +143,13 @@ def filter_may_contain(pair, filt, q):
     ``EMPTY`` keys test False. This is the engine-level pre-filter; the
     in-kernel twin is :func:`kernel.filter_probe_grid`.
     """
-    n_b, fw = filt.shape
+    fw = filt.shape[-1]
     bits_log2 = (fw * 32).bit_length() - 1
     valid = q != EMPTY
     blk = jnp.where(valid, pair.s(q), 0).astype(jnp.int32)
     may = valid
     for p in bloom_positions(q, bits_log2):
-        word = filt[blk, (p >> jnp.uint32(5)).astype(jnp.int32)]
+        word = filt[blk, 0, (p >> jnp.uint32(5)).astype(jnp.int32)]
         may &= ((word >> (p & jnp.uint32(31))) & jnp.uint32(1)) != 0
     return may
 
@@ -359,8 +361,7 @@ def merge_dirty_batch(cfg, state: DeviceTableState, keys, cnts):
     uk, uc, carry_k, carry_c, n_carried = hops.bucket_rows(
         rows, keys, cnts, n_b, cfg.max_updates_per_block)
     nk, nc, nf, spill_k, spill_c = hops.merge_dirty(
-        pair, state.keys, state.counts, state.filter_words, perm, uk, uc,
-        cfg.interpret)
+        pair, state.keys, state.counts, state.filter_words, perm, uk, uc)
     state = state._replace(keys=nk, counts=nc, filter_words=nf)
     state = append_overflow(state, spill_k, spill_c)
     n_dirty = dirty.sum(dtype=jnp.int32)
@@ -399,8 +400,7 @@ def merge_partition(cfg, state: DeviceTableState, p) -> DeviceTableState:
         rows, sk, sc, k, cfg.max_updates_per_block)
     dirty = (p * k + jnp.arange(k)).astype(jnp.int32)
     nk, nc, nf, spill_k, spill_c = hops.merge_dirty(
-        pair, state.keys, state.counts, state.filter_words, dirty, uk, uc,
-        cfg.interpret)
+        pair, state.keys, state.counts, state.filter_words, dirty, uk, uc)
     state = state._replace(keys=nk, counts=nc, filter_words=nf)
     state = append_overflow(state, spill_k, spill_c)
     # carried updates stay staged at the head of the partition

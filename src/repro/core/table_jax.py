@@ -75,7 +75,6 @@ class FlashTableConfig:
     cs_partitions: int = 8        # MDB: change-segment partitions
     max_updates_per_block: int = 1 << 9   # VMEM cap per tile merge
     overflow_capacity: int = 1 << 10
-    interpret: bool = True        # Pallas interpret mode (CPU container)
     filters: bool = True          # consult the blocked-Bloom filters on
                                   # lookups (§12). Maintenance always runs
                                   # (state invariants stay uniform); this
@@ -312,7 +311,7 @@ def lookup_ex(cfg: FlashTableConfig, state: DeviceTableState, q_keys
     q = q_keys.astype(jnp.int32)
     fw = state.filter_words if cfg.filters else None
     cnt, dist, tiles = hops.query_blocked_ex(
-        cfg.pair, state.keys, state.counts, q, 128, cfg.interpret, fw)
+        cfg.pair, state.keys, state.counts, q, 128, fw)
     if cfg.scheme != "MB":  # MB has no change segment to consolidate
         cnt = cnt + seg.scan_segment(state.log_keys.reshape(-1),
                                      state.log_counts.reshape(-1), q)

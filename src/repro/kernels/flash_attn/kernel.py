@@ -8,9 +8,10 @@ cf. EXPERIMENTS.md §Perf granite iteration 1, where the lax.scan
 formulation was refuted because XLA materializes scan carries per step).
 
 MXU alignment: block_q/block_k multiples of 128 on real TPUs (the lane
-dim); head_dim is the minor-most dim of every tile. Validated bit-for-bit
-against ``ref.sdpa_ref`` under ``interpret=True`` (CPU) across
-shape/dtype sweeps in tests/test_flash_attn.py.
+dim); head_dim is the minor-most dim of every tile. Validated against
+``ref.sdpa_ref`` in the Pallas interpreter (CPU) across shape/dtype
+sweeps in tests/test_flash_attn.py; compiled on the TPU
+(:func:`repro.kernels.pallas.pallas_call` decides, from the platform).
 """
 from __future__ import annotations
 
@@ -19,6 +20,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..pallas import pallas_call
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, scale: float,
@@ -67,10 +70,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, scale: float,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_q", "block_k", "causal",
-                                    "interpret"))
+                   static_argnames=("block_q", "block_k", "causal"))
 def flash_attention_fwd(q, k, v, block_q: int = 128, block_k: int = 128,
-                        causal: bool = True, interpret: bool = True):
+                        causal: bool = True):
     """q: (b, s, h, d); k/v: (b, s, kvh, d/dv) → o: (b, s, h, dv).
 
     GQA: query head hq reads kv head hq // (h // kvh).
@@ -92,7 +94,7 @@ def flash_attention_fwd(q, k, v, block_q: int = 128, block_k: int = 128,
 
     kern = functools.partial(_fwd_kernel, block_k=block_k, scale=scale,
                              causal=causal)
-    out = pl.pallas_call(
+    out = pallas_call(
         kern,
         grid=(b * h, s // block_q),
         in_specs=[
@@ -108,6 +110,5 @@ def flash_attention_fwd(q, k, v, block_q: int = 128, block_k: int = 128,
         ],
         out_specs=pl.BlockSpec((1, block_q, dv), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, dv), v.dtype),
-        interpret=interpret,
     )(qt, kt, vt)
     return out.reshape(b, h, s, dv).transpose(0, 2, 1, 3)

@@ -1,7 +1,7 @@
 """jit'd wrapper: flash attention with oracle fallback.
 
-``flash_attention(q, k, v)`` dispatches to the Pallas kernel (interpret
-mode on CPU; compiled Mosaic on real TPUs). The dense oracle lives in
+``flash_attention(q, k, v)`` dispatches to the Pallas kernel (the Pallas
+interpreter on the CPU; compiled Mosaic on the TPU). The dense oracle lives in
 ref.py; tests sweep shapes/dtypes asserting allclose.
 """
 from __future__ import annotations
@@ -11,6 +11,6 @@ from .ref import sdpa_ref  # noqa: F401
 
 
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128):
     return flash_attention_fwd(q, k, v, block_q=block_q, block_k=block_k,
-                               causal=causal, interpret=interpret)
+                               causal=causal)

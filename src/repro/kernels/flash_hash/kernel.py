@@ -1,7 +1,7 @@
 """Pallas TPU kernels for the flash-hash counting table.
 
 TPU adaptation of the paper's block-level update (§2.1): the HBM-resident
-data segment is tiled ``(1, r)`` per grid step — one *flash block* == one
+data segment is tiled one block per grid step — one *flash block* == one
 VMEM tile. The grid walks blocks in ascending order (the paper's
 *semi-random write* discipline → in-order single-store tiles), each tile is
 read and written exactly once per merge (the paper's one-clean-per-block
@@ -10,8 +10,11 @@ over the lane dimension — no scatter, no per-element HBM traffic.
 
 Kernels
 -------
-* ``merge``       — grid over all blocks; per block, fold its (EMPTY-padded)
-  update list into the tile with vectorized cyclic linear probing.
+* ``merge``       — grid over all blocks; per block, fold its update list
+  into the tile with vectorized cyclic linear probing. A list holds its
+  updates first and EMPTY padding after (the layout ``ops.bucket_rows``
+  builds), and the fold stops at the first EMPTY, so a block with no
+  updates costs its tile's DMA and no loop steps.
 * ``merge_dirty`` — beyond-paper variant: grid only over *dirty* blocks via
   a scalar-prefetched block-id list (saves the read+write of clean tiles —
   on-device analogue of "only merge blocks with staged updates").
@@ -19,13 +22,26 @@ Kernels
   pick the tile each query batch reads (PagedAttention-style indexing).
 * ``filter_probe_grid`` — negative-lookup pre-pass (DESIGN.md §12): each
   grid step holds one block's blocked-Bloom filter row (a few uint32
-  lanes — SMEM/VMEM-resident, ~64× smaller than the tile) and answers
-  membership for up to ``qcap`` queries without touching the tile. Both
-  merge kernels OR the inserted keys' Bloom bits into the filter row of
-  exactly the dirty blocks they visit, in the same tile pass.
+  lanes, ~64× smaller than the tile) and answers membership for up to
+  ``qcap`` queries without touching the tile. Both merge kernels OR the
+  inserted keys' Bloom bits into the filter row of exactly the dirty
+  blocks they visit, in the same tile pass.
 
-All kernels run under ``interpret=True`` on CPU for validation; BlockSpecs
-use power-of-two ``r`` (lane-dim multiples of 128 for real TPUs).
+Tile layout
+-----------
+Every per-block array is walked as ``(rows, 1, width)`` with a
+``(1, 1, width)`` block: the block's last two dims equal the array's, which
+is what Mosaic requires of a one-row block (a ``(1, width)`` block of a
+``(rows, width)`` array is refused — the sublane dim must be a multiple of
+8 or the whole dim). On the TPU that array is laid out ``T(1,128)``: no
+padding, one contiguous row per block. The table state is kept in this
+layout (:func:`segments.init_state`); entry points also accept plain
+``(rows, width)`` arrays and return outputs in the shapes they were given.
+Per-update and per-query keys are read as scalars from SMEM windows of
+the same shape — Mosaic has no dynamic lane extract from a vector.
+
+Kernels run compiled on the TPU and in the Pallas interpreter on the CPU
+(:func:`repro.kernels.pallas.pallas_call` decides, from the platform).
 """
 from __future__ import annotations
 
@@ -37,8 +53,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...core.hashing import Pow2Hash, bloom_positions
+from ..pallas import pallas_call
 
 EMPTY = -1
+
+
+def _tiles(x):
+    """View ``(rows, width)`` as the ``(rows, 1, width)`` tile layout (a
+    no-op for arrays already in it)."""
+    return x.reshape(x.shape[0], 1, x.shape[-1])
+
+
+def _row(width: int, index_map, smem: bool = False) -> pl.BlockSpec:
+    """One ``(1, 1, width)`` row per grid step (VMEM, or SMEM for rows the
+    kernel reads scalar by scalar)."""
+    return pl.BlockSpec((1, 1, width), index_map,
+                        memory_space=pltpu.SMEM if smem else None)
 
 
 def _bloom_or_row(filt, aw, u, valid, bits_log2):
@@ -54,12 +84,15 @@ def _bloom_or_row(filt, aw, u, valid, bits_log2):
 
 
 def _bloom_test_row(filt, aw, u, bits_log2):
-    """Test one key against a ``(1, fw)`` filter row (k-probe AND)."""
-    hit = jnp.uint32(1)
+    """Test one key against a ``(1, fw)`` filter row (k-probe AND).
+
+    ``filt`` is the row bit-cast to int32: Mosaic reduces no unsigned
+    ints, and the one-lane select-and-sum extracts the word unchanged."""
+    hit = jnp.int32(1)
     for p in bloom_positions(u, bits_log2):
         w = (p >> jnp.uint32(5)).astype(jnp.int32)
-        word = jnp.sum(jnp.where(aw == w, filt, jnp.uint32(0)))
-        hit &= (word >> (p & jnp.uint32(31))) & jnp.uint32(1)
+        word = jnp.sum(jnp.where(aw == w, filt, 0))
+        hit &= (word >> (p & jnp.uint32(31)).astype(jnp.int32)) & 1
     return hit != 0
 
 
@@ -68,14 +101,12 @@ def _bloom_test_row(filt, aw, u, bits_log2):
 # --------------------------------------------------------------------------
 def _merge_kernel(pair: Pow2Hash, tk_ref, tc_ref, tf_ref, uk_ref, uc_ref,
                   ok_ref, oc_ref, of_ref, sk_ref, sc_ref):
-    r = tk_ref.shape[1]
-    fw = tf_ref.shape[1]
-    max_u = uk_ref.shape[1]
-    keys0 = tk_ref[...]          # (1, r) int32 tile in VMEM
-    counts0 = tc_ref[...]
-    filt0 = tf_ref[...]          # (1, fw) uint32 blocked-Bloom filter row
-    uk = uk_ref[...]             # (1, max_u)
-    uc = uc_ref[...]
+    r = tk_ref.shape[2]
+    fw = tf_ref.shape[2]
+    max_u = uk_ref.shape[2]
+    keys0 = tk_ref[0]            # (1, r) int32 tile in VMEM
+    counts0 = tc_ref[0]
+    filt0 = tf_ref[0]            # (1, fw) uint32 blocked-Bloom filter row
     ar = jax.lax.broadcasted_iota(jnp.int32, (1, r), 1)
     aw = jax.lax.broadcasted_iota(jnp.int32, (1, fw), 1)
     au = jax.lax.broadcasted_iota(jnp.int32, (1, max_u), 1)
@@ -85,8 +116,8 @@ def _merge_kernel(pair: Pow2Hash, tk_ref, tc_ref, tf_ref, uk_ref, uc_ref,
 
     def body(j, carry):
         keys, counts, filt, spill_k, spill_c, n_spill = carry
-        k = jax.lax.dynamic_index_in_dim(uk[0], j, keepdims=False)
-        c = jax.lax.dynamic_index_in_dim(uc[0], j, keepdims=False)
+        k = uk_ref[0, 0, j]                          # scalars from SMEM
+        c = uc_ref[0, 0, j]
         valid = k != EMPTY
         home = (pair.g(k) & rmask).astype(jnp.int32)
         d = (ar - home) & rmask                      # cyclic probe distance
@@ -109,116 +140,99 @@ def _merge_kernel(pair: Pow2Hash, tk_ref, tc_ref, tf_ref, uk_ref, uc_ref,
         n_spill = n_spill + do_spill.astype(jnp.int32)
         return keys, counts, filt, spill_k, spill_c, n_spill
 
-    init = (keys0, counts0, filt0,
+    def more(carry):           # updates are front-packed: stop at EMPTY
+        j = carry[0]
+        return (j < max_u) & (uk_ref[0, 0, jnp.minimum(j, max_u - 1)]
+                              != EMPTY)
+
+    def step(carry):
+        return (carry[0] + 1,) + body(carry[0], carry[1:])
+
+    init = (jnp.int32(0), keys0, counts0, filt0,
             jnp.full((1, max_u), EMPTY, jnp.int32),
             jnp.zeros((1, max_u), counts0.dtype),
             jnp.int32(0))
-    keys, counts, filt, spill_k, spill_c, _ = jax.lax.fori_loop(
-        0, max_u, body, init)
-    ok_ref[...] = keys
-    oc_ref[...] = counts
-    of_ref[...] = filt
-    sk_ref[...] = spill_k
-    sc_ref[...] = spill_c
+    _, keys, counts, filt, spill_k, spill_c, _ = jax.lax.while_loop(
+        more, step, init)
+    ok_ref[0] = keys
+    oc_ref[0] = counts
+    of_ref[0] = filt
+    sk_ref[0] = spill_k
+    sc_ref[0] = spill_c
 
 
-@functools.partial(jax.jit, static_argnums=(0, 6))
+@functools.partial(jax.jit, static_argnums=(0,))
 def merge(pair: Pow2Hash, table_keys, table_counts, filter_words,
-          upd_keys, upd_counts, interpret: bool = True):
+          upd_keys, upd_counts):
     """Merge bucketed updates into the data segment.
 
-    table_keys/table_counts: (n_b, r) int32
-    filter_words:            (n_b, fw) uint32 blocked-Bloom filter rows
-    upd_keys/upd_counts:     (n_b, max_u) int32, EMPTY-padded
-    Returns (new_keys, new_counts, new_filter, spill_keys, spill_counts).
+    table_keys/table_counts: (n_b, r) int32 (or the ``(n_b, 1, r)`` tile
+    layout); filter_words: (n_b, fw) uint32 blocked-Bloom filter rows;
+    upd_keys/upd_counts: (n_b, max_u) int32, each row's updates first,
+    then EMPTY padding (entries after a row's first EMPTY are ignored).
+    Returns (new_keys, new_counts, new_filter, spill_keys, spill_counts),
+    each shaped like the matching input.
     """
-    n_b, r = table_keys.shape
-    _, fw = filter_words.shape
-    _, max_u = upd_keys.shape
-    kern = functools.partial(_merge_kernel, pair)
-    return pl.pallas_call(
-        kern,
+    args = [table_keys, table_counts, filter_words, upd_keys, upd_counts]
+    tiles = [_tiles(a) for a in args]
+    n_b, r = table_keys.shape[0], table_keys.shape[-1]
+    fw = filter_words.shape[-1]
+    max_u = upd_keys.shape[-1]
+    blk = lambda b: (b, 0, 0)
+    specs = [_row(r, blk), _row(r, blk), _row(fw, blk)]
+    outs = pallas_call(
+        functools.partial(_merge_kernel, pair),
         grid=(n_b,),
-        in_specs=[
-            pl.BlockSpec((1, r), lambda b: (b, 0)),
-            pl.BlockSpec((1, r), lambda b: (b, 0)),
-            pl.BlockSpec((1, fw), lambda b: (b, 0)),
-            pl.BlockSpec((1, max_u), lambda b: (b, 0)),
-            pl.BlockSpec((1, max_u), lambda b: (b, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, r), lambda b: (b, 0)),
-            pl.BlockSpec((1, r), lambda b: (b, 0)),
-            pl.BlockSpec((1, fw), lambda b: (b, 0)),
-            pl.BlockSpec((1, max_u), lambda b: (b, 0)),
-            pl.BlockSpec((1, max_u), lambda b: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_b, r), table_keys.dtype),
-            jax.ShapeDtypeStruct((n_b, r), table_counts.dtype),
-            jax.ShapeDtypeStruct((n_b, fw), filter_words.dtype),
-            jax.ShapeDtypeStruct((n_b, max_u), upd_keys.dtype),
-            jax.ShapeDtypeStruct((n_b, max_u), upd_counts.dtype),
-        ],
+        in_specs=specs + [_row(max_u, blk, smem=True)] * 2,
+        out_specs=specs + [_row(max_u, blk)] * 2,
+        out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype) for t in tiles],
         input_output_aliases={0: 0, 1: 1, 2: 2},   # in-place tile update
-        interpret=interpret,
-    )(table_keys, table_counts, filter_words, upd_keys, upd_counts)
+        name="flash_hash_merge",
+    )(*tiles)
+    return [o.reshape(a.shape) for o, a in zip(outs, args)]
 
 
 # --------------------------------------------------------------------------
 # dirty-only merge (beyond-paper §Perf optimization)
 # --------------------------------------------------------------------------
-@functools.partial(jax.jit, static_argnums=(0, 7))
+@functools.partial(jax.jit, static_argnums=(0,))
 def merge_dirty(pair: Pow2Hash, table_keys, table_counts, filter_words,
-                dirty_blocks, upd_keys, upd_counts, interpret: bool = True):
+                dirty_blocks, upd_keys, upd_counts):
     """Like :func:`merge`, but the grid only visits ``dirty_blocks``.
 
-    dirty_blocks: (n_d,) int32 block ids (may repeat the last id as padding —
-    revisiting an already-merged block with EMPTY updates is a no-op).
+    dirty_blocks: (n_d,) int32 distinct block ids (a repeated id would
+    re-read a tile whose write-back may still be in flight).
     upd_keys/upd_counts: (n_d, max_u) updates for the listed blocks.
     The filter rows of exactly the dirty blocks are OR-updated in the same
     pass; clean blocks' rows pass through untouched via the aliasing.
     """
-    n_b, r = table_keys.shape
-    _, fw = filter_words.shape
-    n_d, max_u = upd_keys.shape
+    args = [table_keys, table_counts, filter_words, upd_keys, upd_counts]
+    tiles = [_tiles(a) for a in args]
+    r = table_keys.shape[-1]
+    fw = filter_words.shape[-1]
+    n_d, max_u = upd_keys.shape[0], upd_keys.shape[-1]
 
     def kern(blocks_ref, *refs):  # scalar-prefetch ref only feeds index_maps
         del blocks_ref
         _merge_kernel(pair, *refs)
 
+    tile = lambda i, blocks: (blocks[i], 0, 0)
+    upd = lambda i, blocks: (i, 0, 0)
+    specs = [_row(r, tile), _row(r, tile), _row(fw, tile)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_d,),
-        in_specs=[
-            pl.BlockSpec((1, r), lambda i, blocks: (blocks[i], 0)),
-            pl.BlockSpec((1, r), lambda i, blocks: (blocks[i], 0)),
-            pl.BlockSpec((1, fw), lambda i, blocks: (blocks[i], 0)),
-            pl.BlockSpec((1, max_u), lambda i, blocks: (i, 0)),
-            pl.BlockSpec((1, max_u), lambda i, blocks: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, r), lambda i, blocks: (blocks[i], 0)),
-            pl.BlockSpec((1, r), lambda i, blocks: (blocks[i], 0)),
-            pl.BlockSpec((1, fw), lambda i, blocks: (blocks[i], 0)),
-            pl.BlockSpec((1, max_u), lambda i, blocks: (i, 0)),
-            pl.BlockSpec((1, max_u), lambda i, blocks: (i, 0)),
-        ],
+        in_specs=specs + [_row(max_u, upd, smem=True)] * 2,
+        out_specs=specs + [_row(max_u, upd)] * 2,
     )
-    return pl.pallas_call(
+    outs = pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n_b, r), table_keys.dtype),
-            jax.ShapeDtypeStruct((n_b, r), table_counts.dtype),
-            jax.ShapeDtypeStruct((n_b, fw), filter_words.dtype),
-            jax.ShapeDtypeStruct((n_d, max_u), upd_keys.dtype),
-            jax.ShapeDtypeStruct((n_d, max_u), upd_counts.dtype),
-        ],
+        out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype) for t in tiles],
         input_output_aliases={1: 0, 2: 1, 3: 2},  # offset by scalar-prefetch
-        interpret=interpret,
-    )(dirty_blocks, table_keys, table_counts, filter_words,
-      upd_keys, upd_counts)
+        name="flash_hash_merge_dirty",
+    )(dirty_blocks, *tiles)
+    return [o.reshape(a.shape) for o, a in zip(outs, args)]
 
 
 # --------------------------------------------------------------------------
@@ -227,18 +241,18 @@ def merge_dirty(pair: Pow2Hash, table_keys, table_counts, filter_words,
 def _query_kernel(pair: Pow2Hash, blocks_ref, qk_ref, tk_ref, tc_ref,
                   cnt_ref, dist_ref):
     del blocks_ref  # only used by the index_map
-    r = tk_ref.shape[1]
-    qchunk = qk_ref.shape[1]
-    keys = tk_ref[...]
-    counts = tc_ref[...]
-    qk = qk_ref[...]                              # (1, qchunk)
+    r = tk_ref.shape[2]
+    qchunk = qk_ref.shape[2]
+    keys = tk_ref[0]
+    counts = tc_ref[0]
     ar = jax.lax.broadcasted_iota(jnp.int32, (1, r), 1)
+    au = jax.lax.broadcasted_iota(jnp.int32, (1, qchunk), 1)
     inf = jnp.int32(r + 1)
     rmask = jnp.int32(r - 1)
 
     def one(j, carry):
         cnts, dists = carry
-        k = jax.lax.dynamic_index_in_dim(qk[0], j, keepdims=False)
+        k = qk_ref[0, 0, j]                           # scalar from SMEM
         home = (pair.g(k) & rmask).astype(jnp.int32)
         d = (ar - home) & rmask
         d_match = jnp.min(jnp.where(keys == k, d, inf))
@@ -247,7 +261,6 @@ def _query_kernel(pair: Pow2Hash, blocks_ref, qk_ref, tk_ref, tc_ref,
         hit = (d == d_match) & found
         cnt = jnp.sum(jnp.where(hit, counts, 0))
         dist = jnp.where(found, d_match, jnp.minimum(d_empty, r - 1)) + 1
-        au = jax.lax.broadcasted_iota(jnp.int32, (1, qchunk), 1)
         sel = au == j
         cnts = jnp.where(sel, cnt, cnts)
         dists = jnp.where(sel, dist, dists)
@@ -256,13 +269,12 @@ def _query_kernel(pair: Pow2Hash, blocks_ref, qk_ref, tk_ref, tc_ref,
     cnts0 = jnp.zeros((1, qchunk), counts.dtype)
     dists0 = jnp.zeros((1, qchunk), jnp.int32)
     cnts, dists = jax.lax.fori_loop(0, qchunk, one, (cnts0, dists0))
-    cnt_ref[...] = cnts
-    dist_ref[...] = dists
+    cnt_ref[0] = cnts
+    dist_ref[0] = dists
 
 
-@functools.partial(jax.jit, static_argnums=(0, 5))
-def query_grid(pair: Pow2Hash, table_keys, table_counts, blocks, q2,
-               interpret: bool = True):
+@functools.partial(jax.jit, static_argnums=(0,))
+def query_grid(pair: Pow2Hash, table_keys, table_counts, blocks, q2):
     """Point queries over an explicit chunk layout (the batched entry).
 
     q2: (n_rows, qcap) int32 — grid step ``i`` reads the tile of block
@@ -271,37 +283,34 @@ def query_grid(pair: Pow2Hash, table_keys, table_counts, blocks, q2,
     (callers bucket; :func:`ops.query_blocked` builds this layout).
     Padding lanes (``EMPTY`` or foreign-block keys) produce junk values
     that callers never gather. Sized for large batches: HBM tile traffic
-    is one read per *queried block*, not one per query/chunk."""
-    n_b, r = table_keys.shape
-    n_rows, qcap = q2.shape
-    kern = functools.partial(_query_kernel, pair)
+    is one read per *queried block*, not one per query/chunk.
+    Returns (counts, distances) shaped like ``q2``."""
+    r = table_keys.shape[-1]
+    n_rows, qcap = q2.shape[0], q2.shape[-1]
+    row = lambda i, blocks: (i, 0, 0)
+    tile = lambda i, blocks: (blocks[i], 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_rows,),
-        in_specs=[
-            pl.BlockSpec((1, qcap), lambda i, blocks: (i, 0)),
-            pl.BlockSpec((1, r), lambda i, blocks: (blocks[i], 0)),
-            pl.BlockSpec((1, r), lambda i, blocks: (blocks[i], 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, qcap), lambda i, blocks: (i, 0)),
-            pl.BlockSpec((1, qcap), lambda i, blocks: (i, 0)),
-        ],
+        in_specs=[_row(qcap, row, smem=True), _row(r, tile), _row(r, tile)],
+        out_specs=[_row(qcap, row), _row(qcap, row)],
     )
-    return pl.pallas_call(
-        kern,
+    cnts, dists = pallas_call(
+        functools.partial(_query_kernel, pair),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((n_rows, qcap), table_counts.dtype),
-            jax.ShapeDtypeStruct((n_rows, qcap), jnp.int32),
+            jax.ShapeDtypeStruct((n_rows, 1, qcap), table_counts.dtype),
+            jax.ShapeDtypeStruct((n_rows, 1, qcap), jnp.int32),
         ],
-        interpret=interpret,
-    )(blocks.astype(jnp.int32), q2, table_keys, table_counts)
+        name="flash_hash_query",
+    )(blocks.astype(jnp.int32), _tiles(q2), _tiles(table_keys),
+      _tiles(table_counts))
+    return cnts.reshape(q2.shape), dists.reshape(q2.shape)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 4, 5))
+@functools.partial(jax.jit, static_argnums=(0, 4))
 def query(pair: Pow2Hash, table_keys, table_counts, q_keys,
-          qchunk: int = 128, interpret: bool = True):
+          qchunk: int = 128):
     """Point queries. q_keys: (Q,) int32, Q % qchunk == 0. Queries must be
     pre-sorted so that each chunk hits one block (callers use
     ``ops.query``, which sorts/buckets); here each chunk's block id is the
@@ -312,8 +321,7 @@ def query(pair: Pow2Hash, table_keys, table_counts, q_keys,
     n_chunks = Q // qchunk
     q2 = q_keys.reshape(n_chunks, qchunk)
     blocks = pair.s(q2[:, 0]).astype(jnp.int32)    # (n_chunks,)
-    cnts, dists = query_grid(pair, table_keys, table_counts, blocks, q2,
-                             interpret)
+    cnts, dists = query_grid(pair, table_keys, table_counts, blocks, q2)
     return cnts.reshape(Q), dists.reshape(Q)
 
 
@@ -322,55 +330,51 @@ def query(pair: Pow2Hash, table_keys, table_counts, q_keys,
 # --------------------------------------------------------------------------
 def _filter_probe_kernel(blocks_ref, qk_ref, tf_ref, may_ref):
     del blocks_ref  # only used by the index_map
-    fw = tf_ref.shape[1]
-    qchunk = qk_ref.shape[1]
-    filt = tf_ref[...]                            # (1, fw) uint32 row
-    qk = qk_ref[...]                              # (1, qchunk)
+    fw = tf_ref.shape[2]
+    qchunk = qk_ref.shape[2]
+    filt = jax.lax.bitcast_convert_type(tf_ref[0], jnp.int32)  # (1, fw)
     aw = jax.lax.broadcasted_iota(jnp.int32, (1, fw), 1)
     au = jax.lax.broadcasted_iota(jnp.int32, (1, qchunk), 1)
     fbits_log2 = (fw * 32).bit_length() - 1
 
     def one(j, may):
-        k = jax.lax.dynamic_index_in_dim(qk[0], j, keepdims=False)
+        k = qk_ref[0, 0, j]                       # scalar from SMEM
         hit = _bloom_test_row(filt, aw, k.astype(jnp.uint32), fbits_log2)
         ok = (k != EMPTY) & hit
         return jnp.where(au == j, ok.astype(jnp.int32), may)
 
-    may_ref[...] = jax.lax.fori_loop(
+    may_ref[0] = jax.lax.fori_loop(
         0, qchunk, one, jnp.zeros((1, qchunk), jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnums=(3,))
-def filter_probe_grid(filter_words, blocks, q2, interpret: bool = True):
+@jax.jit
+def filter_probe_grid(filter_words, blocks, q2):
     """Membership pre-pass over the same chunk layout as :func:`query_grid`.
 
     Grid step ``i`` holds only block ``blocks[i]``'s filter row — a few
-    uint32 lanes, SMEM/VMEM-resident, ~``r/fw`` times smaller than the
-    tile — and answers all of row ``i``'s queries against it with zero
-    tile traffic. Returns a ``(n_rows, qcap)`` int32 mask: 0 ⇒ the key is
+    uint32 lanes, ~``r/fw`` times smaller than the tile — and answers all
+    of row ``i``'s queries against it with zero tile traffic. Returns an
+    int32 mask shaped like ``q2``: 0 ⇒ the key is
     definitively absent from the block (and, because staging paths also
     maintain the filter, from the change segment and overflow region
     too); 1 ⇒ maybe present, fetch the tile. Rows must be bucketed like
     :func:`query_grid`'s (``ops.query_blocked`` builds both layouts);
     the Bloom hash ignores the block id, so foreign-lane junk is
     harmless — callers never gather those lanes."""
-    n_b, fw = filter_words.shape
-    n_rows, qcap = q2.shape
+    fw = filter_words.shape[-1]
+    n_rows, qcap = q2.shape[0], q2.shape[-1]
+    row = lambda i, blocks: (i, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_rows,),
-        in_specs=[
-            pl.BlockSpec((1, qcap), lambda i, blocks: (i, 0)),
-            pl.BlockSpec((1, fw), lambda i, blocks: (blocks[i], 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, qcap), lambda i, blocks: (i, 0)),
-        ],
+        in_specs=[_row(qcap, row, smem=True),
+                  _row(fw, lambda i, blocks: (blocks[i], 0, 0))],
+        out_specs=[_row(qcap, row)],
     )
-    (may,) = pl.pallas_call(
+    (may,) = pallas_call(
         _filter_probe_kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((n_rows, qcap), jnp.int32)],
-        interpret=interpret,
-    )(blocks.astype(jnp.int32), q2, filter_words)
-    return may
+        out_shape=[jax.ShapeDtypeStruct((n_rows, 1, qcap), jnp.int32)],
+        name="flash_hash_filter_probe",
+    )(blocks.astype(jnp.int32), _tiles(q2), _tiles(filter_words))
+    return may.reshape(q2.shape)

@@ -106,21 +106,12 @@ def accumulate(tokens) -> Tuple[jax.Array, jax.Array]:
     return keys, cnts.astype(jnp.int32)
 
 
-def merge(pair: Pow2Hash, table_keys, table_counts, filter_words,
-          upd_keys, upd_counts, interpret: bool = True):
-    return _k.merge(pair, table_keys, table_counts, filter_words,
-                    upd_keys, upd_counts, interpret)
+merge = _k.merge
+merge_dirty = _k.merge_dirty
 
 
-def merge_dirty(pair: Pow2Hash, table_keys, table_counts, filter_words,
-                dirty_blocks, upd_keys, upd_counts, interpret: bool = True):
-    return _k.merge_dirty(pair, table_keys, table_counts, filter_words,
-                          dirty_blocks, upd_keys, upd_counts, interpret)
-
-
-@functools.partial(jax.jit, static_argnums=(0, 4))
-def query_sorted(pair: Pow2Hash, table_keys, table_counts, q_keys,
-                 interpret: bool = True):
+@functools.partial(jax.jit, static_argnums=(0,))
+def query_sorted(pair: Pow2Hash, table_keys, table_counts, q_keys):
     """Point queries; sorts by block first so consecutive grid steps reuse
     the same VMEM tile (Pallas elides the re-fetch), then unsorts.
 
@@ -128,16 +119,14 @@ def query_sorted(pair: Pow2Hash, table_keys, table_counts, q_keys,
     use :func:`query_blocked`, which fetches each queried tile once."""
     blk = pair.s(q_keys)
     order = jnp.argsort(blk, stable=True)
-    cnts, dists = _k.query(pair, table_keys, table_counts, q_keys[order],
-                           1, interpret)
+    cnts, dists = _k.query(pair, table_keys, table_counts, q_keys[order], 1)
     inv = jnp.argsort(order, stable=True)
     return cnts[inv], dists[inv]
 
 
-@functools.partial(jax.jit, static_argnums=(0, 4, 5))
+@functools.partial(jax.jit, static_argnums=(0, 4))
 def query_blocked_ex(pair: Pow2Hash, table_keys, table_counts, q_keys,
-                     qcap: int = 128, interpret: bool = True,
-                     filter_words=None):
+                     qcap: int = 128, filter_words=None):
     """Batched point queries, sized for large batches (paper §2.7).
 
     Buckets the batch by destination block into the dense
@@ -167,7 +156,7 @@ def query_blocked_ex(pair: Pow2Hash, table_keys, table_counts, q_keys,
     block tiles the query waves fetched (the batch's accounted
     ``tile_loads``; 0 when the filter killed everything).
     """
-    n_b, _ = table_keys.shape
+    n_b = table_keys.shape[0]
     (Q,) = q_keys.shape
     if Q == 0:
         return (jnp.zeros((0,), table_counts.dtype),
@@ -207,8 +196,7 @@ def query_blocked_ex(pair: Pow2Hash, table_keys, table_counts, q_keys,
     if filter_words is not None:
         def fwave(p, may_s):
             win, dense, g = dense_rows(p, sb, pos, rank, sq)
-            m = _k.filter_probe_grid(filter_words, grid_blocks, dense,
-                                     interpret)
+            m = _k.filter_probe_grid(filter_words, grid_blocks, dense)
             return jnp.where(win, m[g], may_s)
 
         n_fwaves = (max_load + qcap - 1) // qcap
@@ -227,7 +215,7 @@ def query_blocked_ex(pair: Pow2Hash, table_keys, table_counts, q_keys,
         cnt_s, dist_s = acc
         win, dense, g = dense_rows(p, sb, pos, rank, sq)
         c, d = _k.query_grid(pair, table_keys, table_counts, grid_blocks,
-                             dense, interpret)
+                             dense)
         cnt_s = jnp.where(win, c[g], cnt_s)
         dist_s = jnp.where(win, d[g], dist_s)
         return cnt_s, dist_s
@@ -242,9 +230,8 @@ def query_blocked_ex(pair: Pow2Hash, table_keys, table_counts, q_keys,
 
 
 def query_blocked(pair: Pow2Hash, table_keys, table_counts, q_keys,
-                  qcap: int = 128, interpret: bool = True,
-                  filter_words=None):
+                  qcap: int = 128, filter_words=None):
     """:func:`query_blocked_ex` without the tile count (compat entry)."""
     cnts, dists, _ = query_blocked_ex(pair, table_keys, table_counts,
-                                      q_keys, qcap, interpret, filter_words)
+                                      q_keys, qcap, filter_words)
     return cnts, dists
