@@ -45,6 +45,8 @@ from typing import Dict
 
 import numpy as np
 
+from .spans import span
+
 
 @dataclasses.dataclass
 class QueryEngineStats:
@@ -145,25 +147,26 @@ class BatchedQueryEngine:
         self.stats.keys += flat.size
         if flat.size == 0:
             return np.zeros(0, np.int64)
-        uniq, inv = np.unique(flat, return_inverse=True)
-        self.stats.unique_keys += uniq.size
-        ucnt = np.zeros(uniq.size, np.int64)
-        if not self._hot:
-            # cold cache (the steady state under interleaved writes):
-            # skip the per-key probe loop entirely
-            miss_idx = np.flatnonzero(uniq != tj.EMPTY).tolist()
-        else:
-            self._trace("cache_read", "cache", "r")
-            miss_idx = []
-            for i, k in enumerate(uniq):
-                if k == tj.EMPTY:
-                    continue  # padding key: count 0, never probed or cached
-                c = self._hot.get(int(k))
-                if c is None:
-                    miss_idx.append(i)
-                else:
-                    ucnt[i] = c
-                    self.stats.cache_hits += 1
+        with span("query.dedup"):
+            uniq, inv = np.unique(flat, return_inverse=True)
+            self.stats.unique_keys += uniq.size
+            ucnt = np.zeros(uniq.size, np.int64)
+            if not self._hot:
+                # cold cache (the steady state under interleaved writes):
+                # skip the per-key probe loop entirely
+                miss_idx = np.flatnonzero(uniq != tj.EMPTY).tolist()
+            else:
+                self._trace("cache_read", "cache", "r")
+                miss_idx = []
+                for i, k in enumerate(uniq):
+                    if k == tj.EMPTY:
+                        continue  # padding key: count 0, never probed or cached
+                    c = self._hot.get(int(k))
+                    if c is None:
+                        miss_idx.append(i)
+                    else:
+                        ucnt[i] = c
+                        self.stats.cache_hits += 1
         if miss_idx:
             epoch = self._epoch          # fence: inserts only if unchanged
             self._trace("lookup_begin", "state", "r", epoch=epoch)
@@ -175,15 +178,16 @@ class BatchedQueryEngine:
                 # ucnt already holds 0 for those positions.
                 step = self.chunk
                 may = np.empty(miss.size, bool)
-                for lo in range(0, miss.size, step):
-                    part = miss[lo:lo + step]
-                    pad = step - part.size
-                    if pad:
-                        part = np.concatenate(
-                            [part, np.full(pad, tj.EMPTY, np.int64)])
-                    m = np.asarray(
-                        self._filter(state, jnp.asarray(part, jnp.int32)))
-                    may[lo:lo + step - pad] = m[:step - pad].astype(bool)
+                with span("query.filter"):
+                    for lo in range(0, miss.size, step):
+                        part = miss[lo:lo + step]
+                        pad = step - part.size
+                        if pad:
+                            part = np.concatenate(
+                                [part, np.full(pad, tj.EMPTY, np.int64)])
+                        m = np.asarray(self._filter(
+                            state, jnp.asarray(part, jnp.int32)))
+                        may[lo:lo + step - pad] = m[:step - pad].astype(bool)
                 neg = miss[~may]
                 if neg.size:
                     self.stats.filter_negatives += neg.size
@@ -192,8 +196,9 @@ class BatchedQueryEngine:
                         # the next invalidate() evicts them wholesale
                         self._trace("cache_insert", "cache", "w",
                                     epoch=epoch)
-                        for k in neg:
-                            self._remember(int(k), 0)
+                        with span("query.remember"):
+                            for k in neg:
+                                self._remember(int(k), 0)
                     else:
                         self._trace("lookup_fenced", epoch=self._epoch)
                         self.stats.fenced += neg.size
@@ -209,14 +214,16 @@ class BatchedQueryEngine:
                 if pad:  # fixed shapes → one compiled program per table
                     part = np.concatenate(
                         [part, np.full(pad, tj.EMPTY, np.int64)])
-                res = self._lookup(state, jnp.asarray(part, jnp.int32))
-                cnt, dist = res[0], res[1]
-                if len(res) == 3:
-                    # scalar (single table) or per-shard vector (sharded)
-                    self.stats.tile_loads += int(np.asarray(res[2]).sum())
                 n_real = step - pad
-                cnt = np.asarray(cnt)[:n_real]
-                dist = np.asarray(dist)[:n_real]
+                with span("query.lookup"):
+                    res = self._lookup(state, jnp.asarray(part, jnp.int32))
+                    cnt, dist = res[0], res[1]
+                    if len(res) == 3:
+                        # scalar (single table) or per-shard vector
+                        self.stats.tile_loads += int(
+                            np.asarray(res[2]).sum())
+                    cnt = np.asarray(cnt)[:n_real]
+                    dist = np.asarray(dist)[:n_real]
                 got[lo:lo + n_real] = cnt
                 self.stats.device_dispatches += 1
                 self.stats.probe_total += int(dist.sum())
@@ -226,8 +233,9 @@ class BatchedQueryEngine:
             ucnt[miss_idx] = got
             if epoch == self._epoch:
                 self._trace("cache_insert", "cache", "w", epoch=epoch)
-                for k, c in zip(miss, got):
-                    self._remember(int(k), int(c))
+                with span("query.remember"):
+                    for k, c in zip(miss, got):
+                        self._remember(int(k), int(c))
             else:
                 # a drain invalidated mid-lookup: these counts may predate
                 # it, so they must not outlive the invalidation

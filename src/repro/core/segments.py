@@ -99,6 +99,7 @@ def init_state(num_blocks: int, block_entries: int, log_shape,
 # ---------------------------------------------------------------------------
 # per-block blocked-Bloom filter (DESIGN.md §12)
 # ---------------------------------------------------------------------------
+@jax.named_scope("filter_or")
 def filter_or_keys(pair, filt, keys):
     """OR the Bloom bits of ``keys`` into their home blocks' filter rows.
 
@@ -154,6 +155,7 @@ def filter_may_contain(pair, filt, q):
     return may
 
 
+@jax.named_scope("rebuild_filters")
 def rebuild_filters(pair, state: DeviceTableState) -> DeviceTableState:
     """Recompute every filter row from the live segments.
 
@@ -218,6 +220,7 @@ def compact(keys, counts):
 # ---------------------------------------------------------------------------
 # pointer-bumped staging (overflow region + partitioned change segment)
 # ---------------------------------------------------------------------------
+@jax.named_scope("scatter_rows")
 def scatter_rows(buf_keys, buf_counts, ptrs, rows, keys, cnts):
     """Pointer-bumped append of (keys, cnts) into per-row buffers.
 
@@ -255,6 +258,7 @@ def scatter_rows(buf_keys, buf_counts, ptrs, rows, keys, cnts):
     return buf_keys, buf_counts, ptrs + n_fit, rest_k, rest_c, n_fit
 
 
+@jax.named_scope("append_overflow")
 def append_overflow(state: DeviceTableState, spill_k, spill_c
                     ) -> DeviceTableState:
     """Compact spilled entries into the overflow region (page-chained in
@@ -272,6 +276,7 @@ def append_overflow(state: DeviceTableState, spill_k, spill_c
         stats=state.stats._replace(dropped=state.stats.dropped + n_dropped))
 
 
+@jax.named_scope("append_log")
 def append_log(cfg, state: DeviceTableState, keys, cnts) -> DeviceTableState:
     """Append a deduped chunk to the monolithic log (sequential write).
 
@@ -347,21 +352,24 @@ def merge_dirty_batch(cfg, state: DeviceTableState, keys, cnts):
     """
     pair = cfg.pair
     n_b = cfg.num_blocks
-    valid = keys != EMPTY
-    blk = jnp.where(valid, pair.s(keys), 0).astype(jnp.int32)
-    per_block = jnp.zeros((n_b,), jnp.int32).at[blk].add(
-        valid.astype(jnp.int32))
-    dirty = per_block > 0
-    # grid order: dirty blocks (ascending id — the semi-random write
-    # discipline), then clean blocks with EMPTY update rows (no-op visits).
-    perm = jnp.argsort(jnp.where(dirty, 0, 1), stable=True).astype(jnp.int32)
-    inv = jnp.zeros((n_b,), jnp.int32).at[perm].set(
-        jnp.arange(n_b, dtype=jnp.int32))
-    rows = jnp.where(valid, inv[blk], n_b).astype(jnp.int32)
+    with jax.named_scope("dirty_perm"):
+        valid = keys != EMPTY
+        blk = jnp.where(valid, pair.s(keys), 0).astype(jnp.int32)
+        per_block = jnp.zeros((n_b,), jnp.int32).at[blk].add(
+            valid.astype(jnp.int32))
+        dirty = per_block > 0
+        # grid order: dirty blocks (ascending id — the semi-random write
+        # discipline), then clean blocks with EMPTY update rows (no-op visits).
+        perm = jnp.argsort(jnp.where(dirty, 0, 1),
+                           stable=True).astype(jnp.int32)
+        inv = jnp.zeros((n_b,), jnp.int32).at[perm].set(
+            jnp.arange(n_b, dtype=jnp.int32))
+        rows = jnp.where(valid, inv[blk], n_b).astype(jnp.int32)
     uk, uc, carry_k, carry_c, n_carried = hops.bucket_rows(
         rows, keys, cnts, n_b, cfg.max_updates_per_block)
-    nk, nc, nf, spill_k, spill_c = hops.merge_dirty(
-        pair, state.keys, state.counts, state.filter_words, perm, uk, uc)
+    with jax.named_scope("merge_dirty"):
+        nk, nc, nf, spill_k, spill_c = hops.merge_dirty(
+            pair, state.keys, state.counts, state.filter_words, perm, uk, uc)
     state = state._replace(keys=nk, counts=nc, filter_words=nf)
     state = append_overflow(state, spill_k, spill_c)
     n_dirty = dirty.sum(dtype=jnp.int32)
@@ -372,6 +380,7 @@ def merge_dirty_batch(cfg, state: DeviceTableState, keys, cnts):
     return state._replace(stats=stats), carry_k, carry_c
 
 
+@jax.named_scope("drain_log")
 def drain_log(cfg, state: DeviceTableState) -> DeviceTableState:
     """Drain the monolithic log into the data segment (dirty-block merge).
 
@@ -385,6 +394,7 @@ def drain_log(cfg, state: DeviceTableState) -> DeviceTableState:
                           log_ptr=n_carry, stats=stats)
 
 
+@jax.named_scope("merge_partition")
 def merge_partition(cfg, state: DeviceTableState, p) -> DeviceTableState:
     """Drain change-segment partition ``p`` into its ``k`` data blocks.
 
@@ -399,8 +409,9 @@ def merge_partition(cfg, state: DeviceTableState, p) -> DeviceTableState:
     uk, uc, carry_k, carry_c, n_carried = hops.bucket_rows(
         rows, sk, sc, k, cfg.max_updates_per_block)
     dirty = (p * k + jnp.arange(k)).astype(jnp.int32)
-    nk, nc, nf, spill_k, spill_c = hops.merge_dirty(
-        pair, state.keys, state.counts, state.filter_words, dirty, uk, uc)
+    with jax.named_scope("merge_dirty"):
+        nk, nc, nf, spill_k, spill_c = hops.merge_dirty(
+            pair, state.keys, state.counts, state.filter_words, dirty, uk, uc)
     state = state._replace(keys=nk, counts=nc, filter_words=nf)
     state = append_overflow(state, spill_k, spill_c)
     # carried updates stay staged at the head of the partition

@@ -54,6 +54,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .spans import span, traced
 from .table_sim import EMPTY
 
 
@@ -153,7 +154,7 @@ class FlushDispatcher:
             self.trace("job_start", job=job, label=label)
             t0 = time.perf_counter()
             try:
-                with self.lock:
+                with span("drain.job"), self.lock:
                     fn()
             finally:
                 self.trace("job_end", job=job)
@@ -170,7 +171,7 @@ class FlushDispatcher:
                 tr.record("job_start", job=job, label=label)
             t0 = time.perf_counter()
             try:
-                with self.lock:
+                with span("drain.job"), self.lock:
                     fn()
             finally:
                 if tr is not None:
@@ -192,7 +193,8 @@ class FlushDispatcher:
             return
         t0 = time.perf_counter()
         try:
-            f.result()
+            with span("write.wait"):
+                f.result()
         except Exception as exc:
             done, job, label = info if info else ({}, "?", None)
             chunk = f" ({label})" if label else ""
@@ -273,6 +275,7 @@ class SealedFront:
                 else f"hr:inflight[{part}]")
 
     # -- ingest side ---------------------------------------------------------
+    @traced("write.fold")
     def fold(self, uniq: np.ndarray, sums: np.ndarray,
              owners: Optional[np.ndarray] = None) -> Tuple[int, int]:
         """Fold pre-deduped (token, Δ-sum) pairs into the active buffers
@@ -332,6 +335,7 @@ class SealedFront:
                 "replays the WAL)")
 
     # flashlint: quiescent (callers settle first; see the class docstring)
+    @traced("write.seal")
     def seal(self, parts: Optional[List[int]] = None
              ) -> Optional[Dict[int, Tuple[np.ndarray, np.ndarray]]]:
         """Swap the selected partitions' active buffers into the
@@ -379,6 +383,7 @@ class SealedFront:
                 self._wal_seqs[p] = None
 
     # -- read-your-writes ----------------------------------------------------
+    @traced("query.overlay")
     def pending(self, flat: np.ndarray,
                 owners: Optional[np.ndarray] = None) -> np.ndarray:
         # flashlint: under-lock
@@ -1059,22 +1064,24 @@ class ShardedBackend:
         assert_live(self.state)       # off-thread donation guard (§9)
         waves = max(-(-ks.size // step) for ks, _ in per_shard.values())
         for w in range(waves):
-            toks = np.full(n * step, EMPTY, np.int64)
-            dels = np.zeros(n * step, np.int64)
-            for s, (ks, vs) in per_shard.items():
-                part_k = ks[w * step:(w + 1) * step]
-                part_v = vs[w * step:(w + 1) * step]
-                toks[s * step:s * step + part_k.size] = part_k
-                dels[s * step:s * step + part_v.size] = part_v
-            self.state, n_carry = self._upd(self.state,
-                                            jnp.asarray(toks, jnp.int32),
-                                            jnp.asarray(dels, jnp.int32))
-            led.dispatches += 1
-            # owner-aligned placement keeps every (src,dst) bucket within
-            # bucket_cap, so the collective can never carry entries over
-            self.carried += int(np.asarray(n_carry).sum())
+            with span("drain.wave"):
+                toks = np.full(n * step, EMPTY, np.int64)
+                dels = np.zeros(n * step, np.int64)
+                for s, (ks, vs) in per_shard.items():
+                    part_k = ks[w * step:(w + 1) * step]
+                    part_v = vs[w * step:(w + 1) * step]
+                    toks[s * step:s * step + part_k.size] = part_k
+                    dels[s * step:s * step + part_v.size] = part_v
+                self.state, n_carry = self._upd(
+                    self.state, jnp.asarray(toks, jnp.int32),
+                    jnp.asarray(dels, jnp.int32))
+                led.dispatches += 1
+                # owner-aligned placement keeps every (src,dst) bucket
+                # within bucket_cap, so the collective never carries over
+                self.carried += int(np.asarray(n_carry).sum())
         import jax
-        jax.block_until_ready(self.state)   # durable, not merely queued (§9)
+        with span("drain.device_wait"):     # durable, not merely queued (§9)
+            jax.block_until_ready(self.state)
         self._disp.trace("state_rebind", "state", "w")
         self._staged_dirty = True
         for _s, (ks, _vs) in per_shard.items():
@@ -1106,8 +1113,10 @@ class ShardedBackend:
 
         from .distributed import assert_live
         assert_live(self.state)
-        self.state = self._mrg(self.state)
-        jax.block_until_ready(self.state)
+        with span("drain.merge"):
+            self.state = self._mrg(self.state)
+        with span("drain.device_wait"):
+            jax.block_until_ready(self.state)
         self._disp.trace("state_rebind", "state", "w")
         self.stats_ledger.merges += 1
         self._staged_dirty = False
@@ -1200,24 +1209,26 @@ class ShardedBackend:
             ks = np.zeros(0, np.int64)
             vs = np.zeros(0, np.int64)
         for w in range(waves):
-            toks = np.full(n * step, EMPTY, np.int64)
-            dels = np.zeros(n * step, np.int64)
-            ck = ks[w * budget:(w + 1) * budget]
-            cv = vs[w * budget:(w + 1) * budget]
-            for j, s in enumerate(self._local_shards):
-                pk = ck[j * step:(j + 1) * step]
-                pv = cv[j * step:(j + 1) * step]
-                toks[s * step:s * step + pk.size] = pk
-                dels[s * step:s * step + pv.size] = pv
-            gt = D.make_global_batch(self.mesh, self.axis,
-                                     toks.astype(np.int32))
-            gd = D.make_global_batch(self.mesh, self.axis,
-                                     dels.astype(np.int32))
-            self.state, n_carry = self._upd(self.state, gt, gd)
-            led.dispatches += 1
-            self.carried += int(np.asarray(n_carry))
+            with span("drain.wave"):
+                toks = np.full(n * step, EMPTY, np.int64)
+                dels = np.zeros(n * step, np.int64)
+                ck = ks[w * budget:(w + 1) * budget]
+                cv = vs[w * budget:(w + 1) * budget]
+                for j, s in enumerate(self._local_shards):
+                    pk = ck[j * step:(j + 1) * step]
+                    pv = cv[j * step:(j + 1) * step]
+                    toks[s * step:s * step + pk.size] = pk
+                    dels[s * step:s * step + pv.size] = pv
+                gt = D.make_global_batch(self.mesh, self.axis,
+                                         toks.astype(np.int32))
+                gd = D.make_global_batch(self.mesh, self.axis,
+                                         dels.astype(np.int32))
+                self.state, n_carry = self._upd(self.state, gt, gd)
+                led.dispatches += 1
+                self.carried += int(np.asarray(n_carry))
         import jax
-        jax.block_until_ready(self.state)   # durable, not merely queued
+        with span("drain.device_wait"):     # durable, not merely queued
+            jax.block_until_ready(self.state)
         self._disp.trace("state_rebind", "state", "w")
         if waves:
             # other hosts' entries may have landed in our local shards'
@@ -1539,6 +1550,7 @@ class FlashStore:
         self.close()
 
     # -- writes -------------------------------------------------------------
+    @traced("update")
     def update(self, tokens, deltas=None) -> None:
         """Accumulate a (token[, Δ]) batch into H_R. Duplicates fold,
         zero-sum Δs cancel (§2.6), EMPTY tokens are padding; the device
@@ -1552,6 +1564,7 @@ class FlashStore:
         self.update(np.asarray([key], np.int64),
                     np.asarray([delta], np.int64))
 
+    @traced("flush")
     def flush(self, wait: bool = True) -> None:
         """Durability point: drain H_R and force the device merge of any
         staged change segment (end-of-stream / checkpoint).
@@ -1566,6 +1579,7 @@ class FlashStore:
         self._check_open()
         self._b.flush(wait=wait)
 
+    @traced("drain")
     def drain(self, wait: bool = True) -> None:
         """Stage H_R to the device change segment without forcing the
         merge (the cheap half of :meth:`flush`): sealed entries reach
@@ -1575,6 +1589,7 @@ class FlashStore:
         self._b.drain(wait=wait)
 
     # -- reads --------------------------------------------------------------
+    @traced("query")
     def query(self, keys):
         """Counts for ``keys`` — scalar in, ``int`` out; array-like in,
         ``int64`` array out (aligned with the flattened input). Reads are
@@ -1585,6 +1600,7 @@ class FlashStore:
             return int(self._b.query_batch(np.asarray([keys]))[0])
         return self._b.query_batch(keys)
 
+    @traced("query")
     def query_batch(self, keys) -> np.ndarray:
         """Alias of :meth:`query` for unambiguously-batched call sites."""
         self._check_open()

@@ -141,6 +141,7 @@ def init(cfg: FlashTableConfig) -> DeviceTableState:
 # ---------------------------------------------------------------------------
 # MB policy (§2.3): no change segment
 # ---------------------------------------------------------------------------
+@jax.named_scope("mb_merge")
 def _mb_update(cfg: FlashTableConfig, state: DeviceTableState, keys, cnts
                ) -> DeviceTableState:
     """MB: merge the deduped batch immediately.
@@ -164,6 +165,7 @@ def _mb_update(cfg: FlashTableConfig, state: DeviceTableState, keys, cnts
 # ---------------------------------------------------------------------------
 # MDB-L policy (§2.4): monolithic log change segment
 # ---------------------------------------------------------------------------
+@jax.named_scope("stage")
 def _stage(cfg: FlashTableConfig, state: DeviceTableState, keys, cnts
            ) -> DeviceTableState:
     """Append a deduped chunk to the MDB-L log (sequential write).
@@ -200,6 +202,7 @@ def _mdb_merge_where(cfg: FlashTableConfig, state: DeviceTableState, mask
     return jax.lax.fori_loop(0, cfg.cs_partitions, body, state)
 
 
+@jax.named_scope("stage")
 def _mdb_update(cfg: FlashTableConfig, state: DeviceTableState, keys, cnts
                 ) -> DeviceTableState:
     """MDB: stage into per-partition buffers; a partition that cannot fit
@@ -241,10 +244,11 @@ def _mdb_update(cfg: FlashTableConfig, state: DeviceTableState, keys, cnts
 def _update_impl(cfg: FlashTableConfig, state: DeviceTableState, tokens,
                  deltas: Optional[jax.Array] = None) -> DeviceTableState:
     tokens = tokens.astype(jnp.int32)
-    if deltas is None:
-        keys, cnts = hops.accumulate(tokens)
-    else:
-        keys, cnts = accumulate_deltas(tokens, deltas.astype(jnp.int32))
+    with jax.named_scope("accumulate"):
+        if deltas is None:
+            keys, cnts = hops.accumulate(tokens)
+        else:
+            keys, cnts = accumulate_deltas(tokens, deltas.astype(jnp.int32))
     if cfg.scheme == "MB":
         return _mb_update(cfg, state, keys, cnts)
     if cfg.scheme == "MDB":
@@ -310,12 +314,15 @@ def lookup_ex(cfg: FlashTableConfig, state: DeviceTableState, q_keys
     """
     q = q_keys.astype(jnp.int32)
     fw = state.filter_words if cfg.filters else None
-    cnt, dist, tiles = hops.query_blocked_ex(
-        cfg.pair, state.keys, state.counts, q, 128, fw)
+    with jax.named_scope("query_blocked"):
+        cnt, dist, tiles = hops.query_blocked_ex(
+            cfg.pair, state.keys, state.counts, q, 128, fw)
     if cfg.scheme != "MB":  # MB has no change segment to consolidate
-        cnt = cnt + seg.scan_segment(state.log_keys.reshape(-1),
-                                     state.log_counts.reshape(-1), q)
-    cnt = cnt + seg.scan_segment(state.ov_keys, state.ov_counts, q)
+        with jax.named_scope("scan_log"):
+            cnt = cnt + seg.scan_segment(state.log_keys.reshape(-1),
+                                         state.log_counts.reshape(-1), q)
+    with jax.named_scope("scan_overflow"):
+        cnt = cnt + seg.scan_segment(state.ov_keys, state.ov_counts, q)
     return cnt, dist, tiles
 
 
@@ -327,6 +334,7 @@ def lookup(cfg: FlashTableConfig, state: DeviceTableState, q_keys
 
 
 @functools.partial(jax.jit, static_argnums=0)
+@jax.named_scope("filter_probe")
 def filter_probe(cfg: FlashTableConfig, state: DeviceTableState, q_keys
                  ) -> jax.Array:
     """Engine-level may-contain verdicts (one cheap dispatch, no tiles).

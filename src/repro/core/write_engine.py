@@ -58,6 +58,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .spans import holding, span, traced
+
 
 @dataclasses.dataclass
 class WriteEngineStats:
@@ -86,6 +88,7 @@ class WriteEngineStats:
         return dataclasses.asdict(self)
 
 
+@traced("write.dedup")
 def dedup_batch(tokens, deltas, empty: int):
     """Validate and pre-fold one raw writer batch: flatten, drop ``empty``
     padding, and collapse duplicate tokens to (unique, Δ-sum) pairs.
@@ -243,9 +246,12 @@ class BatchedWriteEngine:
     def _inflight(self, value):
         self.front._inflight[0] = value
 
-    def _lock(self):
-        return (self.dispatcher.lock if self.dispatcher is not None
+    def _lock(self, wait_span: Optional[str] = None):
+        """The state lock (a null context without a dispatcher); with
+        ``wait_span``, the wait to take it is timed as that span."""
+        lock = (self.dispatcher.lock if self.dispatcher is not None
                 else contextlib.nullcontext())
+        return lock if wait_span is None else holding(lock, wait_span)
 
     def _submit(self, fn, label: Optional[str] = None) -> None:
         if self.dispatcher is None:
@@ -318,19 +324,21 @@ class BatchedWriteEngine:
         tj.assert_live(self.state)       # off-thread donation guard (§9)
         wear_before = self._tile_stores() if self.on_flush else 0
         step = self.chunk
-        for lo in range(0, keys.size, step):
-            pk = keys[lo:lo + step]
-            pd = dels[lo:lo + step]
-            pad = step - pk.size
-            if pad:  # fixed shapes → one compiled program per table
-                pk = np.concatenate([pk, np.full(pad, tj.EMPTY, np.int64)])
-                pd = np.concatenate([pd, np.zeros(pad, np.int64)])
-            if self.record is not None:
-                self.record.append((pk, pd))
-            self.state = tj.update(self.cfg, self.state,
-                                   jnp.asarray(pk, jnp.int32),
-                                   jnp.asarray(pd, jnp.int32))
-            self.stats.dispatches += 1
+        with span("drain.dispatch"):
+            for lo in range(0, keys.size, step):
+                pk = keys[lo:lo + step]
+                pd = dels[lo:lo + step]
+                pad = step - pk.size
+                if pad:  # fixed shapes → one compiled program per table
+                    pk = np.concatenate(
+                        [pk, np.full(pad, tj.EMPTY, np.int64)])
+                    pd = np.concatenate([pd, np.zeros(pad, np.int64)])
+                if self.record is not None:
+                    self.record.append((pk, pd))
+                self.state = tj.update(self.cfg, self.state,
+                                       jnp.asarray(pk, jnp.int32),
+                                       jnp.asarray(pd, jnp.int32))
+                self.stats.dispatches += 1
         if self.dispatcher is not None:
             # store contract (DESIGN.md §9): a completed drain means the
             # device really holds the entries — not merely that they sit
@@ -338,7 +346,8 @@ class BatchedWriteEngine:
             # wait; the sync baseline pays it inline (that is the stall
             # double buffering exists to hide). Engines without a
             # dispatcher keep the bare pre-PR5 dispatch-and-go.
-            self._jax.block_until_ready(self.state)
+            with span("drain.device_wait"):
+                self._jax.block_until_ready(self.state)
         self.stats.dispatched_entries += keys.size
         self._trace("state_rebind", "state", "w")
         self._staged_dirty = True
@@ -355,9 +364,11 @@ class BatchedWriteEngine:
         tj = self._tj
         tj.assert_live(self.state)
         wear_before = self._tile_stores() if self.on_flush else 0
-        self.state = tj.flush(self.cfg, self.state)
+        with span("drain.merge"):
+            self.state = tj.flush(self.cfg, self.state)
         if self.dispatcher is not None:
-            self._jax.block_until_ready(self.state)   # durable, not queued
+            with span("drain.device_wait"):           # durable, not queued
+                self._jax.block_until_ready(self.state)
         self._trace("state_rebind", "state", "w")
         self.stats.merges += 1
         self._staged_dirty = False
@@ -449,7 +460,7 @@ class BatchedWriteEngine:
         if self.query_engine is None:
             raise ValueError("no paired query engine; construct with "
                              "query_engine=BatchedQueryEngine(cfg)")
-        with self._lock():
+        with self._lock("query.lock"):
             base = self.query_engine.query_batch(self.state, keys)
             pend = self.pending(keys)
         return base + pend

@@ -33,6 +33,7 @@ EMPTY = _k.EMPTY
 
 
 @functools.partial(jax.jit, static_argnums=(3, 4))
+@jax.named_scope("bucket_rows")
 def bucket_rows(rows, keys, counts, n_rows: int, max_u: int):
     """Pack (keys, counts) updates into (n_rows, max_u) per-row buffers.
 
@@ -200,8 +201,9 @@ def query_blocked_ex(pair: Pow2Hash, table_keys, table_counts, q_keys,
             return jnp.where(win, m[g], may_s)
 
         n_fwaves = (max_load + qcap - 1) // qcap
-        may_s = jax.lax.fori_loop(0, n_fwaves, fwave,
-                                  jnp.zeros((Q,), jnp.int32))
+        with jax.named_scope("filter_pass"):
+            may_s = jax.lax.fori_loop(0, n_fwaves, fwave,
+                                      jnp.zeros((Q,), jnp.int32))
         may = jnp.zeros((Q,), jnp.int32).at[order].set(may_s)
         # re-bucket the survivors: fully-filtered blocks vanish from the
         # grid list (no tile fetch) and the post-filter max_load shrinks
@@ -221,9 +223,11 @@ def query_blocked_ex(pair: Pow2Hash, table_keys, table_counts, q_keys,
         return cnt_s, dist_s
 
     n_waves = (max_load + qcap - 1) // qcap
-    cnt_s, dist_s = jax.lax.fori_loop(
-        0, n_waves, wave,
-        (jnp.zeros((Q,), table_counts.dtype), jnp.zeros((Q,), jnp.int32)))
+    with jax.named_scope("query_waves"):
+        cnt_s, dist_s = jax.lax.fori_loop(
+            0, n_waves, wave,
+            (jnp.zeros((Q,), table_counts.dtype),
+             jnp.zeros((Q,), jnp.int32)))
     cnts = jnp.zeros((Q,), table_counts.dtype).at[order].set(cnt_s)
     dists = jnp.zeros((Q,), jnp.int32).at[order].set(dist_s)
     return cnts, dists, n_tiles
