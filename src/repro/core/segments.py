@@ -11,12 +11,13 @@ host-side H_R in front of them.
 
 Shared primitives
 -----------------
-* :func:`scatter_rows`   — pointer-bumped append into per-row buffers.
-  One code path serves both the overflow region (one row) and the MDB
-  partitioned change segment (``cs_partitions`` rows); the old
-  ``_append_overflow`` / ``_mdb_scatter`` twins collapsed into it.
+* :func:`scatter_rows`   — pointer-bumped append into per-row buffers:
+  the MDB partitioned change segment (``cs_partitions`` rows).
 * :func:`append_overflow` / :func:`append_log` /
   :func:`scatter_partitions` — the three staging surfaces.
+  ``append_overflow`` compacts a merge's front-packed spill by per-row
+  counts, so its cost follows the spill's rows and the region's
+  capacity, not the spill array's slots.
 * :func:`merge_dirty_batch` / :func:`drain_log` /
   :func:`merge_partition` — the merge paths (all through the
   ``merge_dirty`` Pallas kernel; wear accounted per dirty block).
@@ -230,7 +231,7 @@ def scatter_rows(buf_keys, buf_counts, ptrs, rows, keys, cnts):
     Entries are packed at their row's pointer in stable input order — the
     paper's semi-random page-write discipline. Entries past a row's
     capacity do *not* fit and are returned for the caller to handle
-    (retry after a drain, or count as dropped).
+    (retry after a drain).
 
     Returns ``(buf_keys, buf_counts, new_ptrs, rest_keys, rest_cnts,
     n_fit)``: rest_* hold the non-fitting entries (EMPTY-masked, same
@@ -261,19 +262,35 @@ def scatter_rows(buf_keys, buf_counts, ptrs, rows, keys, cnts):
 @jax.named_scope("append_overflow")
 def append_overflow(state: DeviceTableState, spill_k, spill_c
                     ) -> DeviceTableState:
-    """Compact spilled entries into the overflow region (page-chained in
+    """Compact a merge's spill into the overflow region (page-chained in
     the paper; a pointer-bumped array here). Entries past the capacity
-    are genuine losses, surfaced in ``stats.dropped``."""
-    flat_k = spill_k.reshape(-1)
-    flat_c = spill_c.reshape(-1)
-    ov_k, ov_c, ptrs, rest_k, _, _ = scatter_rows(
-        state.ov_keys[None, :], state.ov_counts[None, :],
-        state.ov_ptr[None], jnp.zeros(flat_k.shape, jnp.int32),
-        flat_k, flat_c)
-    n_dropped = (rest_k != EMPTY).sum(dtype=jnp.int32)
+    are genuine losses, surfaced in ``stats.dropped``.
+
+    ``spill_k``/``spill_c`` are the merge kernel's ``(n_rows, 1, max_u)``
+    spill, each row front-packed (its spills in columns ``[0, n_i)``,
+    EMPTY after). The spill is appended in row-major order: each overflow
+    slot finds its source row by a binary search of the rows' running
+    spill counts, so the cost is ``n_rows`` counts plus one gather per
+    slot, however few entries the merge spills.
+    """
+    n_rows = spill_k.shape[0]
+    sk = spill_k.reshape(n_rows, -1)
+    sc = spill_c.reshape(n_rows, -1)
+    cap = state.ov_keys.shape[0]
+    per_row = (sk != EMPTY).sum(axis=1, dtype=jnp.int32)
+    ends = jnp.cumsum(per_row, dtype=jnp.int32)
+    total = ends[-1]
+    n_fit = jnp.minimum(jnp.maximum(cap - state.ov_ptr, 0), total)
+    j = jnp.arange(cap, dtype=jnp.int32) - state.ov_ptr  # spill offset
+    take = (j >= 0) & (j < n_fit)
+    row = jnp.minimum(jnp.searchsorted(ends, j, side="right"), n_rows - 1)
+    col = jnp.clip(j - ends[row] + per_row[row], 0, sk.shape[1] - 1)
     return state._replace(
-        ov_keys=ov_k[0], ov_counts=ov_c[0], ov_ptr=ptrs[0],
-        stats=state.stats._replace(dropped=state.stats.dropped + n_dropped))
+        ov_keys=jnp.where(take, sk[row, col], state.ov_keys),
+        ov_counts=jnp.where(take, sc[row, col], state.ov_counts),
+        ov_ptr=state.ov_ptr + n_fit,
+        stats=state.stats._replace(
+            dropped=state.stats.dropped + total - n_fit))
 
 
 @jax.named_scope("append_log")

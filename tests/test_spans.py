@@ -147,9 +147,9 @@ def _op_path_parts(lowered) -> set:
 @pytest.mark.parametrize("scheme,scopes", [
     ("MDB-L", {"accumulate", "stage", "append_log", "filter_or",
                "drain_log", "dirty_perm", "bucket_rows", "merge_dirty",
-               "append_overflow", "scatter_rows"}),
+               "append_overflow"}),
     ("MB", {"accumulate", "mb_merge", "dirty_perm", "bucket_rows",
-            "merge_dirty", "append_overflow", "scatter_rows"}),
+            "merge_dirty", "append_overflow"}),
     ("MDB", {"accumulate", "stage", "scatter_rows", "filter_or",
              "merge_partition", "bucket_rows", "merge_dirty",
              "append_overflow"}),
@@ -160,6 +160,9 @@ def test_update_program_names_its_steps(scheme, scopes):
     toks = np.zeros(256, np.int32)
     parts = _op_path_parts(tj.update.lower(cfg, state, toks))
     assert scopes <= parts, sorted(scopes - parts)
+    # the overflow append compacts by per-row counts; only MDB's
+    # partitioned change segment stages through scatter_rows
+    assert ("scatter_rows" in parts) == (scheme == "MDB")
     if scheme != "MB":
         flush = _op_path_parts(tj.flush.lower(cfg, state))
         merge = "drain_log" if scheme == "MDB-L" else "merge_partition"
